@@ -21,25 +21,29 @@ bench_regeneration._timeit_pair): on burstable hosts, timing one side
 to completion and then the other skews the ratio by whichever capacity
 window each phase landed in.
 
-The measurement runs in a SUBPROCESS with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` — the parent
-bench process keeps the host's real device topology (jax locks the
-device count at first init).
+The bench runs in the calling process over ``jax.devices()`` (a chip
+belongs to one process, so it never respawns itself): mesh sizes larger
+than the device count are skipped.  On a CPU host, set
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before the first
+jax import to get eight virtual devices.
 """
 import json
 import os
-import pathlib
-import subprocess
-import sys
 import time
 
+import jax
+import numpy as np
+
+from benchmarks import _timing
+from repro.core.circulant import CodeSpec
+from repro.exec import plan
+from repro.kernels import dispatch
+
 MESHES = (1, 2, 4, 8)
-_INNER_ENV = "_BENCH_SHARD_INNER"
 
 
 def _timeit_pair(fn_a, fn_b, reps=2, rounds=10):
     """Best-of timing of two alternatives in alternating rounds."""
-    import jax
     jax.block_until_ready(fn_a())          # warm-up: compile + first call
     jax.block_until_ready(fn_b())
     best_a = best_b = float("inf")
@@ -57,15 +61,7 @@ def _timeit_pair(fn_a, fn_b, reps=2, rounds=10):
     return best_a, best_b
 
 
-def _inner(fast: bool) -> dict:
-    import jax
-    import numpy as np
-
-    from benchmarks import _timing
-    from repro.core.circulant import CodeSpec
-    from repro.exec import plan
-    from repro.kernels import dispatch
-
+def run(fast: bool = False, quiet: bool = False) -> dict:
     k = 8
     enc_symbols = 1 << 20       # large enough that shards beat one body
     rep_symbols = 1 << 18
@@ -73,7 +69,7 @@ def _inner(fast: bool) -> dict:
     spec = CodeSpec.make(k, 257)
     n = spec.n
     c = tuple(int(x) for x in spec.c)
-    be = dispatch.get("jnp-int32")
+    be = dispatch.select(257, k)
     rng = _timing.rng()
     data = rng.integers(0, 257, (n, enc_symbols), dtype=np.int64
                         ).astype(np.int32)
@@ -90,10 +86,13 @@ def _inner(fast: bool) -> dict:
     want_reg = ref.regenerate_batch(rmat, rprev, downs).host()
 
     cpus = os.cpu_count() or 1
-    rec = {"n_devices": len(jax.devices()), "host_cpus": cpus,
+    n_dev = len(jax.devices())
+    rec = {"n_devices": n_dev, "host_cpus": cpus,
+           "platform": jax.devices()[0].platform,
+           "device_kind": jax.devices()[0].device_kind,
            "k": k, "n": n, "enc_stream_mb": round(enc_mb, 2),
            "backend": be.name, "encode": [], "repair": []}
-    for m in MESHES:
+    for m in (m for m in MESHES if m <= n_dev):
         pl = plan.get_planner(be, 257, mesh=m)
         # bit-exact parity gates the timing: a wrong fast number is
         # worse than no number
@@ -124,11 +123,13 @@ def _inner(fast: bool) -> dict:
                               "speedup_vs_1dev": round(r1 / rm, 2)})
     rec["parity_ok"] = True
     rec["steady_recompiles"] = 0
-    speedup4 = next(r["speedup_vs_1dev"] for r in rec["encode"]
-                    if r["mesh"] == 4)
+    speedup4 = next((r["speedup_vs_1dev"] for r in rec["encode"]
+                     if r["mesh"] == 4), None)
     rec["encode_speedup_4dev"] = speedup4
-    rec["scaling_asserted"] = cpus >= 4
-    if cpus >= 4:
+    rec["scaling_asserted"] = speedup4 is not None and cpus >= 4
+    if speedup4 is None:
+        rec["scaling_skip_reason"] = f"only {n_dev} device(s): no 4-way mesh"
+    elif cpus >= 4:
         assert speedup4 >= 2.0, \
             f"4-device encode only {speedup4}x single-device (need >= 2x)"
     else:
@@ -139,23 +140,6 @@ def _inner(fast: bool) -> dict:
             f"instead")
         assert speedup4 >= 1.0, \
             f"4-device encode regressed to {speedup4}x single-device"
-    return rec
-
-
-def run(fast: bool = False, quiet: bool = False) -> dict:
-    env = dict(os.environ)
-    env[_INNER_ENV] = "fast" if fast else "full"
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    root = pathlib.Path(__file__).resolve().parent.parent
-    env["PYTHONPATH"] = os.pathsep.join(
-        [str(root / "src"), str(root), env.get("PYTHONPATH", "")])
-    res = subprocess.run([sys.executable, "-m", "benchmarks.bench_shard"],
-                         capture_output=True, text=True, env=env,
-                         cwd=root, timeout=900)
-    if res.returncode != 0:
-        raise RuntimeError(f"bench_shard subprocess failed:\n{res.stdout}\n"
-                           f"{res.stderr}")
-    rec = json.loads(res.stdout.splitlines()[-1])
     if not quiet:
         for erow, rrow in zip(rec["encode"], rec["repair"]):
             print(f"  mesh={erow['mesh']}: encode {erow['mbps']} MB/s "
@@ -165,8 +149,4 @@ def run(fast: bool = False, quiet: bool = False) -> dict:
 
 
 if __name__ == "__main__":
-    mode = os.environ.get(_INNER_ENV)
-    if mode is None:
-        print(json.dumps(run(), indent=1))
-    else:
-        print(json.dumps(_inner(fast=mode == "fast")))
+    print(json.dumps(run(), indent=1))

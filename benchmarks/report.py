@@ -115,9 +115,11 @@ def _bench_headline(stem: str, rec) -> str:
                     f"{rec['corrupt_storm']['corrupt_served']}, shed="
                     f"{rec['overload']['shed']} (typed)")
         if stem == "BENCH_shard":
-            e4 = next(r for r in rec["encode"] if r["mesh"] == 4)
+            e4 = next((r for r in rec["encode"] if r["mesh"] == 4), None)
             bar = ("asserted" if rec["scaling_asserted"]
                    else f"skipped: {rec.get('scaling_skip_reason')}")
+            if e4 is None:
+                return f"no 4-device mesh ({bar})"
             return (f"4-device encode {e4['mbps']} MB/s "
                     f"({e4['speedup_vs_1dev']}x vs 1-device, 2x bar {bar}); "
                     f"parity_ok={rec['parity_ok']}, steady recompiles "

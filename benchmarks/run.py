@@ -23,6 +23,7 @@ from benchmarks import (bench_checkpoint, bench_cluster, bench_codes,
                         bench_field_size, bench_pipeline,
                         bench_regeneration, bench_repair_bandwidth,
                         bench_serve, bench_shard, bench_store, roofline)
+from repro.exec.compile_cache import enable_compile_cache
 
 OUT = pathlib.Path(__file__).resolve().parent / "results"
 REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
@@ -54,6 +55,7 @@ def main() -> None:
     ap.add_argument("--quiet", action="store_true",
                     help="suppress per-row prints (CI smoke mode)")
     args = ap.parse_args()
+    enable_compile_cache()
     quiet = args.quiet
     OUT.mkdir(exist_ok=True)
     check_results_dir()

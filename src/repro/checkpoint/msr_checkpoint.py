@@ -717,6 +717,9 @@ class MSRCheckpointer:
                              for i in rest}
                 r_prev = result(fut_prev)
                 next_data = np.stack([result(x) for x in futs_help])
+                # a landed future keeps its block alive: drop each one as
+                # soon as its rows are copied (the state can be GBs)
+                del futs_help
                 pair = self._regenerate_tiled(pipe, f, r_prev, next_data)
                 a_new, r_new = pair[0], pair[1]
                 af, rf = self._node_files(step, f)
@@ -728,7 +731,8 @@ class MSRCheckpointer:
                 have[f - 1] = a_new
                 for i in range(1, n + 1):
                     idx = i - 1
-                    data[idx] = have[idx] if idx in have else result(futs_rest[i])
+                    data[idx] = have[idx] if idx in have \
+                        else result(futs_rest.pop(i))
                 path = "regenerate"
             else:
                 use = alive[:k]                      # sorted by construction
@@ -751,6 +755,7 @@ class MSRCheckpointer:
                 gf.unpack257_rows(np.stack([lo for lo, _ in packed]),
                                   [hi for _, hi in packed],
                                   out=downloads[k:])
+                del futs, futs_r, packed
                 if repair and failed:
                     # one decode matmul yields the data AND every lost pair
                     mat = self.code.repair.decode_repair_matrix(
@@ -774,7 +779,7 @@ class MSRCheckpointer:
             # context exit joins the repaired-pair writes
 
         treedef = jax.tree_util.tree_structure(template)
-        state = placement.blocks_to_pytree(data.astype(np.int32), treedef, tspec)
+        state = placement.blocks_to_pytree(data, treedef, tspec)
         total = 2 * n * tspec.block_symbols          # ~bytes (packed storage)
         report = RestoreReport(step=step, path=path,
                                failed_nodes=tuple(failed),

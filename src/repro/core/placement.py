@@ -160,9 +160,12 @@ def pytree_to_bytes(tree: Any) -> tuple[bytes, jax.tree_util.PyTreeDef, list[dic
 def bytes_to_leaves(payload: bytes, metas: list[dict]) -> list[np.ndarray]:
     leaves, off = [], 0
     for m in metas:
-        raw = payload[off: off + m["nbytes"]]
+        dt = np.dtype(m["dtype"])
+        # read in place (a bytes slice would copy every leaf once more)
+        arr = np.frombuffer(payload, dtype=dt, count=m["nbytes"] // dt.itemsize,
+                            offset=off) if m["nbytes"] else np.empty(0, dt)
         off += m["nbytes"]
-        leaves.append(np.frombuffer(raw, dtype=np.dtype(m["dtype"])).reshape(m["shape"]).copy())
+        leaves.append(arr.reshape(m["shape"]).copy())
     return leaves
 
 
@@ -170,10 +173,10 @@ def pytree_to_blocks(tree: Any, n: int, p: int = gf.DEFAULT_P,
                      ) -> tuple[np.ndarray, jax.tree_util.PyTreeDef, TreeSpec]:
     """Serialize a pytree into (n, S) GF(p) data blocks a_0..a_{n-1}."""
     payload, treedef, metas = pytree_to_bytes(tree)
-    sym = gf.bytes_to_symbols(payload, p)
-    pad = (-len(sym)) % n
-    sym = np.pad(sym, (0, pad))
-    blocks = sym.reshape(n, -1).astype(np.int32)
+    # one int32 allocation: the widen, the pad to a multiple of n and the
+    # (n, S) layout land in a single write (the state can be GBs)
+    blocks = np.empty((n, -(-len(payload) // n)), np.int32)
+    gf.bytes_to_symbols_into(payload, blocks.reshape(-1), p)
     spec = TreeSpec(treedef_repr=str(treedef), leaves=metas,
                     total_bytes=len(payload), n_blocks=n,
                     block_symbols=blocks.shape[1])
@@ -183,8 +186,8 @@ def pytree_to_blocks(tree: Any, n: int, p: int = gf.DEFAULT_P,
 def blocks_to_pytree(blocks: np.ndarray, treedef: jax.tree_util.PyTreeDef,
                      spec: TreeSpec) -> Any:
     """Inverse of pytree_to_blocks.  Pure byte reads for systematic blocks."""
-    sym = np.asarray(blocks).reshape(-1)
-    payload = gf.symbols_to_bytes(sym)[: spec.total_bytes]
+    sym = np.asarray(blocks).reshape(-1)[: spec.total_bytes]
+    payload = gf.symbols_to_bytes(sym)
     leaves = bytes_to_leaves(payload, spec.leaves)
     return jax.tree_util.tree_unflatten(treedef, leaves)
 

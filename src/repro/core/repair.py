@@ -288,7 +288,6 @@ class RepairEngine:
         self._rmat_np = build_repair_matrix(spec)
         self._rmat = jnp.asarray(self._rmat_np)
         self.decode_cache = DecodeInverseCache(spec, maxsize=inverse_cache_size)
-        self._batch_vmap_ok = jittable
         self.planner = planner
 
     def _planned(self) -> bool:
@@ -382,8 +381,8 @@ class RepairEngine:
 
         The stream axis is processed in ``tile_symbols`` tiles (bounds the
         device working set; XLA pipelines the per-tile dispatches).  The
-        node axis is vmapped through the backend matmul; backends whose
-        kernels don't trace under vmap fall back to per-node dispatch.
+        node axis is vmapped through the backend matmul; only custom
+        (non-jittable) matmuls dispatch per node.
         """
         r_prevs = jnp.asarray(r_prevs, jnp.int32)
         next_data = jnp.asarray(next_data, jnp.int32)
@@ -401,12 +400,9 @@ class RepairEngine:
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-1)
 
     def _regen_tile_batch(self, nodes, r_prevs, next_data) -> jnp.ndarray:
-        if self._batch_vmap_ok:
-            try:
-                return _fused_regenerate_vmapped(self._mm, self._rmat,
-                                                 r_prevs, next_data, self.p)
-            except NotImplementedError:   # trace-time: a primitive in the
-                self._batch_vmap_ok = False   # backend has no batching rule
+        if self._jittable:
+            return _fused_regenerate_vmapped(self._mm, self._rmat,
+                                             r_prevs, next_data, self.p)
         return jnp.stack([self.regenerate_stacked(i, r_prevs[f], next_data[f])
                           for f, i in enumerate(nodes)])
 

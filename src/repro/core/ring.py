@@ -23,10 +23,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:                                    # JAX >= 0.4.35 exports it at top level
-    from jax import shard_map
-except ImportError:                     # older JAX: experimental namespace
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .circulant import CodeSpec

@@ -43,7 +43,8 @@ def checked_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
             f"{have} are available; on CPU set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={want} BEFORE the "
             f"first jax import")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

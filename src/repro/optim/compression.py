@@ -17,10 +17,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-try:                                    # JAX >= 0.4.35 exports it at top level
-    from jax import shard_map
-except ImportError:                     # older JAX: experimental namespace
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

@@ -17,7 +17,7 @@ layer states that once, declaratively:
   planner looks the rule up by op name instead of hand-writing specs at
   every call site;
 * :func:`shard_body` — wraps a dispatch-layer kernel in
-  ``jax.shard_map`` under the rule's specs (``check_rep=False``: the
+  ``jax.shard_map`` under the rule's specs (``check_vma=False``: the
   bodies are pure per-shard maps, there is no replication to verify);
 * :func:`use_mesh` / :func:`current_mesh` — ambient-mesh context so
   stores / checkpointers / codes built inside a ``use_mesh(...)`` block
@@ -40,10 +40,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                                    # jax >= 0.4.35 top-level export
-    from jax import shard_map as _shard_map
-except ImportError:                     # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 STREAM_AXIS = "stream"
 
@@ -212,12 +209,12 @@ register_rule(ShardingRule(
 
 def shard_body(fn: Callable, op: str, mesh: StreamMesh) -> Callable:
     """Wrap a dispatch-layer kernel body in ``shard_map`` under the
-    registered rule for ``op``.  ``check_rep=False``: the bodies are
+    registered rule for ``op``.  ``check_vma=False``: the bodies are
     per-shard maps with no collectives, so there is no replication
     invariant to verify (and skipping the check keeps tracing cheap)."""
     rule = get_rule(op)
     return _shard_map(fn, mesh=mesh.mesh, in_specs=rule.in_specs,
-                      out_specs=rule.out_specs, check_rep=False)
+                      out_specs=rule.out_specs, check_vma=False)
 
 
 # ------------------------------------------------------------ ambient mesh

@@ -94,7 +94,8 @@ def test_ring_encode_various_sizes():
 def test_int8_ring_mean_close_to_true_mean():
     run_subprocess("""
         from repro.optim.compression import int8_ring_mean
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         rng = np.random.default_rng(1)
         x = rng.normal(size=(8, 4096)).astype(np.float32)
         got = np.asarray(int8_ring_mean(jnp.asarray(x), mesh, "data"))
@@ -113,7 +114,8 @@ def test_sharded_train_step_runs_on_host_mesh():
     run_subprocess("""
         from jax.sharding import PartitionSpec as P
         import jax
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         from repro.configs import get_config
         from repro.models import Model
         from repro.optim import adamw

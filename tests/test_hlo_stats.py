@@ -149,7 +149,8 @@ def test_dryrun_artifacts_consistency():
             n_layers=2, d_model=32, n_heads=4, n_kv_heads=2, head_dim=8,
             d_ff=64, vocab_size=256, loss_chunk=16)
         shape = ShapeConfig("train_smoke", 64, 8, "train")
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         rec = dryrun.lower_cell("qwen3-4b", "train_smoke",
                                 cfg=cfg, shape=shape, mesh=mesh)
         json.dump(rec, sys.stdout)

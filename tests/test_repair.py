@@ -14,7 +14,7 @@ import pytest
 from repro.core import gf
 from repro.core.circulant import CodeSpec
 from repro.core.msr import DoubleCirculantMSR
-from repro.core.repair import build_repair_matrix
+from repro.core.repair import RepairEngine, build_repair_matrix
 
 # native `pallas` needs a real TPU; interpret mode covers its semantics here
 BACKENDS = ["jnp-int32", "jnp-f32", "pallas-interpret"]
@@ -121,6 +121,28 @@ def test_regenerate_batch_subset_and_shape_validation():
                                       np.asarray(data[i - 1]))
     with pytest.raises(ValueError):
         code.regenerate_batch([2], r_prevs, next_all)   # F mismatch
+
+
+def test_regenerate_batch_does_not_hide_a_vmap_failure():
+    """A jittable engine whose matmul has no batching rule raises: only
+    engines built with ``jittable=False`` dispatch node by node."""
+    from jax.extend.core import Primitive
+    from jax.interpreters import mlir
+
+    def impl(a, b):
+        return jnp.einsum("mk,kn->mn", a, b) % 257
+
+    prim = Primitive("gf_matmul_without_batching_rule")
+    prim.def_impl(impl)
+    prim.def_abstract_eval(lambda a, b: jax.core.ShapedArray(
+        (a.shape[0], b.shape[1]), jnp.int32))
+    mlir.register_lowering(prim, mlir.lower_fun(impl,
+                                                multiple_results=False))
+    engine = RepairEngine(CodeSpec.make(2, 257),
+                          lambda a, b, p: prim.bind(a, b))
+    with pytest.raises(NotImplementedError):
+        engine.regenerate_batch([1, 2], np.zeros((2, 16), np.int32),
+                                np.zeros((2, 2, 16), np.int32))
 
 
 # ------------------------------------------------------- decode-inverse LRU
