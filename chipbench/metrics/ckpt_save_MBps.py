@@ -1,0 +1,6 @@
+"""ckpt_save_MBps: state bytes of every save begun in the window, over
+the time from the window's start to the end of the last of them."""
+
+
+def read(ctx):
+    return ctx.rate_MBps("save")
