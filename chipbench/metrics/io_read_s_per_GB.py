@@ -1,0 +1,9 @@
+"""io_read_s_per_GB: seconds the program's ``read`` stage recorded
+during the window (each node file and manifest read by the local blob
+backend, summed over the I/O pool's threads), per GB of the cell's
+work."""
+
+
+def read(ctx):
+    secs = ctx.stage_delta.get("read", 0.0)
+    return ctx.per_GB(secs) if secs > 0 else None

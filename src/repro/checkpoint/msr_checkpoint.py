@@ -75,6 +75,7 @@ from repro.core.circulant import CodeSpec
 from repro.core.msr import DoubleCirculantMSR
 from repro.exec.pipeline import Pipeline
 from repro.exec.plan import planning_enabled
+from repro.exec.staging import staged
 from repro.io.blob import BlobBackend, LocalBlob
 from repro.io.retry import RetryPolicy, RetryStats
 
@@ -85,21 +86,32 @@ SAVE_TILE_SYMBOLS = 1 << 20
 _STEP_DIR_RE = re.compile(r"step_(\d+)$")
 
 
+# The node-file encoders and CRCs below are the "format" stage, each
+# span counting the bytes it produces.
 def _npy_bytes(arr: np.ndarray) -> bytes:
-    buf = _pyio.BytesIO()
-    np.save(buf, arr)
-    return buf.getvalue()
+    with staged("format") as span:
+        buf = _pyio.BytesIO()
+        np.save(buf, arr)
+        out = buf.getvalue()
+        span.nbytes = len(out)
+    return out
 
 
 def _npz_bytes(**arrs: np.ndarray) -> bytes:
-    buf = _pyio.BytesIO()
-    np.savez(buf, **arrs)
-    return buf.getvalue()
+    with staged("format") as span:
+        buf = _pyio.BytesIO()
+        np.savez(buf, **arrs)
+        out = buf.getvalue()
+        span.nbytes = len(out)
+    return out
 
 
 def _crc_data(block: np.ndarray) -> int:
     """Content CRC of a systematic block (over its stored uint8 bytes)."""
-    return zlib.crc32(np.ascontiguousarray(block, np.uint8).tobytes())
+    with staged("format") as span:
+        raw = np.ascontiguousarray(block, np.uint8).tobytes()
+        span.nbytes = len(raw)
+        return zlib.crc32(raw)
 
 
 def _crc_red(low: np.ndarray, hi: np.ndarray) -> int:
@@ -107,8 +119,11 @@ def _crc_red(low: np.ndarray, hi: np.ndarray) -> int:
     (low, hi) payload, NOT the .npz container bytes, so a bit-exact
     repair rewrite keeps the manifest CRC valid without a manifest
     rewrite."""
-    c = zlib.crc32(np.ascontiguousarray(low, np.uint8).tobytes())
-    return zlib.crc32(np.ascontiguousarray(hi, np.int64).tobytes(), c)
+    with staged("format") as span:
+        raw_low = np.ascontiguousarray(low, np.uint8).tobytes()
+        raw_hi = np.ascontiguousarray(hi, np.int64).tobytes()
+        span.nbytes = len(raw_low) + len(raw_hi)
+        return zlib.crc32(raw_hi, zlib.crc32(raw_low))
 
 
 def _snapshot_leaf(x):
@@ -269,6 +284,11 @@ class MSRCheckpointer:
     # ------------------------------------------------------------------ paths
     def _step_dir(self, step: int) -> pathlib.Path:
         return self.dir / f"step_{step:06d}"
+
+    def _where(self) -> str:
+        """Where the checkpoints live: the directory, or the store's
+        object prefix (span metadata)."""
+        return str(self.dir) if self.dir is not None else self._prefix
 
     def _okey(self, step: int, name: str) -> str:
         """Store-object key for one piece of a checkpoint step."""
@@ -469,8 +489,12 @@ class MSRCheckpointer:
             The manifest written alongside the node files (code spec +
             tree metadata).
         """
-        if self._store is not None:
-            return self._save_store(step, state)
+        with staged("ckpt.save", step=step, path=self._where()):
+            if self._store is not None:
+                return self._save_store(step, state)
+            return self._save_dir(step, state)
+
+    def _save_dir(self, step: int, state: Any) -> dict:
         n = self.spec.n
         blocks, treedef, tspec = placement.pytree_to_blocks(state, n, self.spec.p)
         d = self._step_dir(step)
@@ -521,9 +545,10 @@ class MSRCheckpointer:
                 "c": list(self.spec.c), "tree": tspec.to_json(),
                 "crc": dict(sorted(crcs.items())),
             }
-            self._write_blob(tmp / "manifest.json",
-                             json.dumps(manifest).encode())
-            self._commit_dir(tmp, d)
+            with staged("ckpt.commit", step=step):
+                self._write_blob(tmp / "manifest.json",
+                                 json.dumps(manifest).encode())
+                self._commit_dir(tmp, d)
         except Exception:
             # best-effort immediate GC; a hard crash leaves the orphan
             # for recover() instead
@@ -574,18 +599,19 @@ class MSRCheckpointer:
         self.iob.fsync_dir(final.parent)
 
     def _gc(self):
-        steps = self.steps()
-        for s in steps[: -self.keep_last]:
-            if self._store is not None:
-                pre = self._okey(s, "")
-                for key in self._store.keys():
-                    if key.startswith(pre):
-                        self._store.delete(key)
-            else:
-                try:
-                    self.iob.rmtree(self._step_dir(s))
-                except OSError:
-                    pass
+        with staged("ckpt.gc"):
+            steps = self.steps()
+            for s in steps[: -self.keep_last]:
+                if self._store is not None:
+                    pre = self._okey(s, "")
+                    for key in self._store.keys():
+                        if key.startswith(pre):
+                            self._store.delete(key)
+                else:
+                    try:
+                        self.iob.rmtree(self._step_dir(s))
+                    except OSError:
+                        pass
 
     # ------------------------------------------------------------- block I/O
     def _read_block(self, ref) -> tuple[np.ndarray, int]:
@@ -598,6 +624,8 @@ class MSRCheckpointer:
         receipt — systematic or degraded, whatever the store served).
         Every checkpoint read path funnels through here via
         :class:`_MeteredReader` so the byte meters can't drift apart.
+        Widening a node file's bytes to int32 symbols is the "pack"
+        stage.
         """
         if isinstance(ref, str):
             res = self._store.get_ext(ref)
@@ -605,9 +633,13 @@ class MSRCheckpointer:
         if ref.suffix == ".npz":
             z = self._load(ref)
             low, hi = z["low"], z["hi"]
-            return gf.unpack257(low, hi), low.nbytes + hi.nbytes
+            with staged("pack"):
+                sym = gf.unpack257(low, hi)
+            return sym, low.nbytes + hi.nbytes
         arr = self._load(ref)
-        return arr.astype(np.int32), arr.nbytes
+        with staged("pack"):
+            sym = arr.astype(np.int32)
+        return sym, arr.nbytes
 
     def _read_packed(self, ref) -> tuple[tuple[np.ndarray, np.ndarray], int]:
         """One packed redundancy read -> ((low, hi), bytes) — the raw
@@ -681,8 +713,14 @@ class MSRCheckpointer:
         """
         if step is None:
             step = self.steps()[-1]
-        if self._store is not None:
-            return self._restore_store(template, step, failed_nodes)
+        with staged("ckpt.restore", step=step, path=self._where()):
+            if self._store is not None:
+                return self._restore_store(template, step, failed_nodes)
+            return self._restore_dir(template, step, failed_nodes, repair)
+
+    def _restore_dir(self, template: Any, step: int,
+                     failed_nodes: Sequence[int], repair: bool,
+                     ) -> tuple[Any, RestoreReport]:
         d = self._step_dir(step)
         manifest = json.loads(self._read_bytes(d / "manifest.json"))
         tspec = placement.TreeSpec.from_json(manifest["tree"])
@@ -701,7 +739,8 @@ class MSRCheckpointer:
             if not failed:
                 futs = [read_async(self._node_files(step, i)[0])
                         for i in range(1, n + 1)]
-                data = np.stack([result(f) for f in futs])
+                with staged("assemble"):
+                    data = np.stack([result(f) for f in futs])
                 path = "systematic"
             elif len(failed) == 1 and repair:
                 f = failed[0]
@@ -716,23 +755,26 @@ class MSRCheckpointer:
                 futs_rest = {i: read_async(self._node_files(step, i)[0])
                              for i in rest}
                 r_prev = result(fut_prev)
-                next_data = np.stack([result(x) for x in futs_help])
+                with staged("assemble"):
+                    next_data = np.stack([result(x) for x in futs_help])
                 # a landed future keeps its block alive: drop each one as
                 # soon as its rows are copied (the state can be GBs)
                 del futs_help
                 pair = self._regenerate_tiled(pipe, f, r_prev, next_data)
                 a_new, r_new = pair[0], pair[1]
                 af, rf = self._node_files(step, f)
-                low, hi = gf.pack257(r_new)
+                with staged("pack"):
+                    low, hi = gf.pack257(r_new)
                 pipe.submit(self._write_node_pair, af, rf, a_new, low, hi)
                 repaired.append(f)
-                data = np.zeros((n, tspec.block_symbols), np.int32)
-                have = dict(zip(plan.data_indices, next_data))
-                have[f - 1] = a_new
-                for i in range(1, n + 1):
-                    idx = i - 1
-                    data[idx] = have[idx] if idx in have \
-                        else result(futs_rest.pop(i))
+                with staged("assemble"):
+                    data = np.zeros((n, tspec.block_symbols), np.int32)
+                    have = dict(zip(plan.data_indices, next_data))
+                    have[f - 1] = a_new
+                    for i in range(1, n + 1):
+                        idx = i - 1
+                        data[idx] = have[idx] if idx in have \
+                            else result(futs_rest.pop(i))
                 path = "regenerate"
             else:
                 use = alive[:k]                      # sorted by construction
@@ -749,8 +791,9 @@ class MSRCheckpointer:
                 downloads = (pool.acquire((2 * k, s_sym), np.int32)
                              if pool is not None
                              else np.empty((2 * k, s_sym), np.int32))
-                for j, x in enumerate(futs):
-                    downloads[j] = result(x)
+                with staged("assemble"):
+                    for j, x in enumerate(futs):
+                        downloads[j] = result(x)
                 packed = [result(x) for x in futs_r]
                 gf.unpack257_rows(np.stack([lo for lo, _ in packed]),
                                   [hi for _, hi in packed],
@@ -858,7 +901,8 @@ class MSRCheckpointer:
             pair = self._regenerate_tiled(pipe, node, r_prev,
                                           np.stack(helpers))
             af, rf = self._node_files(step, node)
-            low, hi = gf.pack257(pair[1])
+            with staged("pack"):
+                low, hi = gf.pack257(pair[1])
             pipe.submit(self._write_node_pair, af, rf, pair[0], low, hi)
         return reader.bytes_read
 

@@ -17,7 +17,6 @@ Everything here is pure JAX (jit/vmap/shard_map friendly).  Host-side helpers
 """
 from __future__ import annotations
 
-from time import perf_counter
 from typing import Sequence
 
 import jax.numpy as jnp
@@ -25,13 +24,6 @@ import numpy as np
 
 DEFAULT_P = 257
 
-
-def record_stage(name: str, seconds: float) -> None:
-    # lazy import: the stage clock lives in repro.exec.staging and core
-    # carries no module-level edge into exec (same pattern as the
-    # envelope import below)
-    from repro.exec.staging import record_stage as rec
-    rec(name, seconds)
 
 # Max number of accumulation terms an int32 lane can hold before a `mod p`
 # fold is due: 32767 terms for p = 257 (the lazy mod-folding envelope,
@@ -262,11 +254,12 @@ def bytes_to_symbols_into(data: bytes | np.ndarray, out: np.ndarray,
     if out.dtype != np.int32 or out.ndim != 1 or out.size < arr.size:
         raise ValueError(f"need flat int32 out of >= {arr.size} symbols, "
                          f"got {out.dtype} {out.shape}")
-    from time import perf_counter
-    t0 = perf_counter()
-    out[:arr.size] = arr
-    out[arr.size:] = 0
-    record_stage("pack", perf_counter() - t0)
+    # lazy import: the stage clock lives in repro.exec.staging and core
+    # carries no module-level edge into exec (as with the envelope)
+    from repro.exec.staging import staged
+    with staged("pack"):
+        out[:arr.size] = arr
+        out[arr.size:] = 0
     return out
 
 
@@ -316,19 +309,19 @@ def pack257_rows(sym: np.ndarray, *, out: np.ndarray | None = None,
         raise ValueError(f"expected (n, S) block matrix, got {sym.shape}")
     if sym.min(initial=0) < 0 or sym.max(initial=0) > 256:
         raise ValueError("symbols out of GF(257) range")
-    t0 = perf_counter()
-    if out is None:
-        low = (sym & 0xFF).astype(np.uint8)   # 256 -> 0, others unchanged
-    else:
-        if out.shape != sym.shape or out.dtype != np.uint8:
-            raise ValueError(f"out must be uint8 {sym.shape}, got "
-                             f"{out.dtype} {out.shape}")
-        np.copyto(out, sym, casting="unsafe")
-        low = out
-    rows, cols = np.nonzero(sym == 256)
-    splits = np.searchsorted(rows, np.arange(1, sym.shape[0]))
-    his = np.split(cols.astype(np.int64), splits)
-    record_stage("pack", perf_counter() - t0)
+    from repro.exec.staging import staged
+    with staged("pack"):
+        if out is None:
+            low = (sym & 0xFF).astype(np.uint8)   # 256 -> 0, others unchanged
+        else:
+            if out.shape != sym.shape or out.dtype != np.uint8:
+                raise ValueError(f"out must be uint8 {sym.shape}, got "
+                                 f"{out.dtype} {out.shape}")
+            np.copyto(out, sym, casting="unsafe")
+            low = out
+        rows, cols = np.nonzero(sym == 256)
+        splits = np.searchsorted(rows, np.arange(1, sym.shape[0]))
+        his = np.split(cols.astype(np.int64), splits)
     return low, his
 
 
@@ -336,18 +329,18 @@ def unpack257_rows(low: np.ndarray, his: Sequence[np.ndarray], *,
                    out: np.ndarray | None = None) -> np.ndarray:
     """Inverse of pack257_rows.  ``out`` (int32, same shape) receives
     the expansion in place — pooled-buffer staging for restore/scrub."""
-    t0 = perf_counter()
-    if out is None:
-        out = np.asarray(low).astype(np.int32)
-    else:
-        low = np.asarray(low)
-        if out.shape != low.shape or out.dtype != np.int32:
-            raise ValueError(f"out must be int32 {low.shape}, got "
-                             f"{out.dtype} {out.shape}")
-        np.copyto(out, low)
-    for i, hi in enumerate(his):
-        out[i, hi] = 256
-    record_stage("pack", perf_counter() - t0)
+    from repro.exec.staging import staged
+    with staged("pack"):
+        if out is None:
+            out = np.asarray(low).astype(np.int32)
+        else:
+            low = np.asarray(low)
+            if out.shape != low.shape or out.dtype != np.int32:
+                raise ValueError(f"out must be int32 {low.shape}, got "
+                                 f"{out.dtype} {out.shape}")
+            np.copyto(out, low)
+        for i, hi in enumerate(his):
+            out[i, hi] = 256
     return out
 
 

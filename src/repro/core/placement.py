@@ -146,15 +146,24 @@ def max_shares_per_rack(layout: RackLayout,
 
 
 def pytree_to_bytes(tree: Any) -> tuple[bytes, jax.tree_util.PyTreeDef, list[dict]]:
-    leaves, treedef = jax.tree_util.tree_flatten(tree)
-    metas, chunks = [], []
-    for leaf in leaves:
-        arr = np.asarray(leaf)
-        raw = arr.tobytes()
-        metas.append({"dtype": str(arr.dtype), "shape": list(arr.shape),
-                      "nbytes": len(raw)})
-        chunks.append(raw)
-    return b"".join(chunks), treedef, metas
+    """The leaves' raw bytes joined in pytree order, with the treedef and
+    per-leaf metadata.  Runs as the "serialize" stage; each device leaf
+    pulled to the host is a "d2h" span counting its bytes."""
+    from repro.exec.staging import staged
+    with staged("serialize"):
+        leaves, treedef = jax.tree_util.tree_flatten(tree)
+        metas, chunks = [], []
+        for leaf in leaves:
+            if isinstance(leaf, jax.Array):
+                with staged("d2h", nbytes=leaf.nbytes):
+                    arr = np.asarray(leaf)
+            else:
+                arr = np.asarray(leaf)
+            raw = arr.tobytes()
+            metas.append({"dtype": str(arr.dtype), "shape": list(arr.shape),
+                          "nbytes": len(raw)})
+            chunks.append(raw)
+        return b"".join(chunks), treedef, metas
 
 
 def bytes_to_leaves(payload: bytes, metas: list[dict]) -> list[np.ndarray]:
@@ -185,11 +194,16 @@ def pytree_to_blocks(tree: Any, n: int, p: int = gf.DEFAULT_P,
 
 def blocks_to_pytree(blocks: np.ndarray, treedef: jax.tree_util.PyTreeDef,
                      spec: TreeSpec) -> Any:
-    """Inverse of pytree_to_blocks.  Pure byte reads for systematic blocks."""
-    sym = np.asarray(blocks).reshape(-1)[: spec.total_bytes]
-    payload = gf.symbols_to_bytes(sym)
-    leaves = bytes_to_leaves(payload, spec.leaves)
-    return jax.tree_util.tree_unflatten(treedef, leaves)
+    """Inverse of pytree_to_blocks.  Pure byte reads for systematic blocks.
+    Runs as the "deserialize" stage, narrowing the symbols back to bytes
+    as a "pack" span inside it."""
+    from repro.exec.staging import staged
+    with staged("deserialize"):
+        sym = np.asarray(blocks).reshape(-1)[: spec.total_bytes]
+        with staged("pack"):
+            payload = gf.symbols_to_bytes(sym)
+        leaves = bytes_to_leaves(payload, spec.leaves)
+        return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 __all__ = ["TreeSpec", "RackLayout", "rack_layout", "rotate_placement",

@@ -33,7 +33,6 @@ from __future__ import annotations
 import threading
 from collections import deque
 from concurrent.futures import Future, ThreadPoolExecutor
-from time import perf_counter
 from typing import Callable, Iterable, Optional, Sequence
 
 from . import staging
@@ -184,9 +183,9 @@ class Pipeline:
         timed_read = None
         if read is not None:
             def timed_read(it):
-                t0 = perf_counter()
-                data = read(it)
-                self._acct("t_stage_read", perf_counter() - t0)
+                with staging.staged("pipe.read") as span:
+                    data = read(it)
+                self._acct("t_stage_read", span.seconds)
                 return data
 
         # depth 1 is the true serial baseline: no prefetch, reads run
@@ -198,9 +197,9 @@ class Pipeline:
                 read_futs[j] = self._pool().submit(timed_read, items[j])
 
         def _consume(it0, out0):
-            t0 = perf_counter()
-            consume(it0, out0)
-            self._acct("t_consume", perf_counter() - t0)
+            with staging.staged("pipe.consume") as span:
+                consume(it0, out0)
+            self._acct("t_consume", span.seconds)
 
         pending: deque = deque()
         try:
@@ -214,12 +213,10 @@ class Pipeline:
                     if ahead and nxt < len(items):
                         read_futs[nxt] = self._pool().submit(
                             timed_read, items[nxt])
-                    t0 = perf_counter()
-                    out = compute(item, data)
-                else:
-                    t0 = perf_counter()
-                    out = compute(item)
-                self._acct("t_dispatch", perf_counter() - t0)
+                with staging.staged("pipe.dispatch") as span:
+                    out = compute(item) if read is None \
+                        else compute(item, data)
+                self._acct("t_dispatch", span.seconds)
                 pending.append((item, out))
                 while len(pending) >= self.depth:
                     it0, out0 = pending.popleft()

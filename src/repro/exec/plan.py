@@ -52,14 +52,13 @@ from __future__ import annotations
 import contextlib
 import math
 import threading
-from time import perf_counter
 from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .staging import StagingPool, record_stage
+from .staging import StagingPool, staged
 
 # Ladder defaults: buckets 4096, 8192, 16384, ... — stream extents below
 # the floor all share the smallest executable, and a ratio-2 ladder
@@ -186,8 +185,12 @@ class PlanResult:
         conversion proves the compute consumed them (DESIGN.md §16.2).
         A PlanResult dropped without ``host()`` simply strands its
         buffers — the pool never reissues an unreleased buffer, so that
-        is safe, just not free."""
-        out = np.asarray(self.raw)
+        is safe, just not free.  The pull is the "d2h" stage, counting
+        the raw (padded) result's bytes."""
+        raw = self.raw
+        with staged("d2h", nbytes=raw.nbytes
+                    if isinstance(raw, jax.Array) else None):
+            out = np.asarray(raw)
         if self._release is not None:
             rel, self._release = self._release, None
             rel()
@@ -220,16 +223,15 @@ def _pad_last(arr: np.ndarray, bucket: int,
     s = arr.shape[-1]
     if s == bucket:
         return arr
-    t0 = perf_counter()
-    if pool is None:
-        out = np.zeros(arr.shape[:-1] + (bucket,), np.int32)
-        out[..., :s] = arr
-    else:
-        out = pool.acquire(arr.shape[:-1] + (bucket,), np.int32)
-        out[..., :s] = arr
-        out[..., s:] = 0            # reused buffer: tail must be re-zeroed
-        bufs.append(out)
-    record_stage("pad", perf_counter() - t0)
+    with staged("pad"):
+        if pool is None:
+            out = np.zeros(arr.shape[:-1] + (bucket,), np.int32)
+            out[..., :s] = arr
+        else:
+            out = pool.acquire(arr.shape[:-1] + (bucket,), np.int32)
+            out[..., :s] = arr
+            out[..., s:] = 0        # reused buffer: tail must be re-zeroed
+            bufs.append(out)
     return out
 
 
@@ -243,17 +245,16 @@ def _pad_both(arr: np.ndarray, f_bucket: int, s_bucket: int,
     f, s = arr.shape[0], arr.shape[-1]
     if f == f_bucket and s == s_bucket:
         return arr
-    t0 = perf_counter()
-    shape = (f_bucket,) + arr.shape[1:-1] + (s_bucket,)
-    if pool is None:
-        out = np.zeros(shape, np.int32)
-        out[:f, ..., :s] = arr
-    else:
-        out = pool.acquire(shape, np.int32)
-        out[...] = 0
-        out[:f, ..., :s] = arr
-        bufs.append(out)
-    record_stage("pad", perf_counter() - t0)
+    with staged("pad"):
+        shape = (f_bucket,) + arr.shape[1:-1] + (s_bucket,)
+        if pool is None:
+            out = np.zeros(shape, np.int32)
+            out[:f, ..., :s] = arr
+        else:
+            out = pool.acquire(shape, np.int32)
+            out[...] = 0
+            out[:f, ..., :s] = arr
+            bufs.append(out)
     return out
 
 
@@ -320,13 +321,6 @@ class PlanCache:
         self.hits = 0
         self.misses = 0
         self.compiles = 0
-        # per code-family accounting (DESIGN.md §15.4): ops dispatched
-        # with a `tag` (the family identity string) count under that
-        # tag; untagged ops — the pre-existing double-circulant paths —
-        # under "default".  Tagged ops also mix the tag into the plan
-        # key, so families with overlapping shapes never share (or
-        # fight over) an executable slot.
-        self.family_stats: dict[str, list[int]] = {}
 
     # ------------------------------------------------------------- plumbing
     def bucket(self, s: int) -> int:
@@ -371,23 +365,24 @@ class PlanCache:
                          out_shardings=self.mesh.sharding(rule.out_specs))
         return jf.lower(*self._i32(*shapes)).compile()
 
-    def _exe(self, key: tuple, build: Callable[[], Callable],
-             tag: Optional[str] = None) -> Callable:
-        fam = tag or "default"
+    def _exe(self, key: tuple, build: Callable[[], Callable]) -> Callable:
         with self._lock:
-            row = self.family_stats.setdefault(fam, [0, 0, 0])
             exe = self._plans.get(key)
             if exe is not None:
                 self.hits += 1
-                row[0] += 1
                 return exe
             self.misses += 1
-            row[1] += 1
             exe = build()
             self.compiles += 1
-            row[2] += 1
             self._plans[key] = exe
             return exe
+
+    @staticmethod
+    def _run(exe: Callable, *operands: np.ndarray):
+        """Call ``exe`` on host operands: the "h2d" stage, counting the
+        bytes handed to the device, padding included."""
+        with staged("h2d", nbytes=sum(x.nbytes for x in operands)):
+            return exe(*operands)
 
     def _releaser(self, bufs: list) -> Optional[Callable]:
         """A PlanResult release hook recycling ``bufs`` (pooled pad
@@ -404,24 +399,17 @@ class PlanCache:
 
     @staticmethod
     def _tagged(key: tuple, tag: Optional[str]) -> tuple:
-        """Mix a family tag into a plan key.  ``None`` (every
-        pre-existing caller) leaves the key byte-identical — no
+        """Mix a family tag into a plan key, so families with
+        overlapping shapes never share an executable slot.  ``None``
+        (every pre-existing caller) leaves the key byte-identical — no
         recompiles ride along with the tagging feature."""
         return key if tag is None else key + (tag,)
 
     def plan_stats(self) -> PlanStats:
         return PlanStats(self.hits, self.misses, self.compiles)
 
-    def plan_stats_by_family(self) -> dict[str, PlanStats]:
-        """Per-family hit/miss/compile counters (ops dispatched without
-        a tag land under ``"default"``)."""
-        with self._lock:
-            return {fam: PlanStats(*row)
-                    for fam, row in sorted(self.family_stats.items())}
-
     def reset_stats(self) -> None:
         self.hits = self.misses = self.compiles = 0
-        self.family_stats = {}
 
     def clear(self) -> None:
         with self._lock:
@@ -439,8 +427,8 @@ class PlanCache:
         combined decode+re-encode matrix, row subsets for degraded
         reads); its shape is part of the plan key, its VALUES are not.
         Only ``blocks`` (the stream operand) is padded and donated.
-        ``tag`` is the dispatching code family's identity — mixed into
-        the plan key and the per-family stats (DESIGN.md §15.4).
+        ``tag`` is the dispatching code family's identity, mixed into
+        the plan key (DESIGN.md §15.4).
         """
         mat = np.asarray(mat, np.int32)
         blocks = np.asarray(blocks, np.int32)
@@ -464,7 +452,7 @@ class PlanCache:
 
         bufs: list = []
         padded = _pad_last(blocks, pad, self.staging, bufs)
-        return PlanResult(self._exe(key, build, tag)(mat, padded), s,
+        return PlanResult(self._run(self._exe(key, build), mat, padded), s,
                           release=self._releaser(bufs))
 
     def circulant_encode(self, data, c, *, tag: Optional[str] = None,
@@ -492,7 +480,7 @@ class PlanCache:
 
         bufs: list = []
         padded = _pad_last(data, pad, self.staging, bufs)
-        return PlanResult(self._exe(key, build, tag)(padded), s,
+        return PlanResult(self._run(self._exe(key, build), padded), s,
                           release=self._releaser(bufs))
 
     def regenerate(self, rmat, r_prev, next_data) -> PlanResult:
@@ -517,8 +505,9 @@ class PlanCache:
                                  (rmat.shape, (pad,), (k, pad)), donate)
 
         bufs: list = []
-        return PlanResult(self._exe(key, build)(
-            rmat, _pad_last(r_prev, pad, self.staging, bufs),
+        return PlanResult(self._run(
+            self._exe(key, build), rmat,
+            _pad_last(r_prev, pad, self.staging, bufs),
             _pad_last(next_data, pad, self.staging, bufs)), s,
             release=self._releaser(bufs))
 
@@ -556,8 +545,9 @@ class PlanCache:
                                  donate)
 
         bufs: list = []
-        return PlanResult(self._exe(key, build)(
-            rmat, _pad_both(r_prevs, fb, pad, self.staging, bufs),
+        return PlanResult(self._run(
+            self._exe(key, build), rmat,
+            _pad_both(r_prevs, fb, pad, self.staging, bufs),
             _pad_both(next_data, fb, pad, self.staging, bufs)), s, batch=f,
             release=self._releaser(bufs))
 
@@ -605,8 +595,9 @@ class PlanCache:
             pm[:f] = mats
             mats = pm
         bufs: list = []
-        return PlanResult(self._exe(key, build, tag)(
-            mats, _pad_both(blocks, fb, pad, self.staging, bufs)),
+        return PlanResult(self._run(
+            self._exe(key, build), mats,
+            _pad_both(blocks, fb, pad, self.staging, bufs)),
             s, batch=f, release=self._releaser(bufs))
 
     def _regen_fn(self):
@@ -657,22 +648,6 @@ def plan_stats() -> PlanStats:
     return PlanStats(h, m, c)
 
 
-def plan_stats_by_family() -> dict[str, PlanStats]:
-    """Per-family hit/miss/compile counters aggregated over every live
-    planner (DESIGN.md §15.4) — untagged double-circulant traffic lands
-    under ``"default"``, each other family under its identity string."""
-    agg: dict[str, list[int]] = {}
-    with _LOCK:
-        planners = list(_REGISTRY.values())
-    for pc in planners:
-        for fam, st in pc.plan_stats_by_family().items():
-            row = agg.setdefault(fam, [0, 0, 0])
-            row[0] += st.hits
-            row[1] += st.misses
-            row[2] += st.compiles
-    return {fam: PlanStats(*row) for fam, row in sorted(agg.items())}
-
-
 def reset_plan_stats() -> None:
     with _LOCK:
         planners = list(_REGISTRY.values())
@@ -692,7 +667,7 @@ __all__ = [
     "BUCKET_MIN", "BUCKET_RATIO", "BATCH_BUCKET_MIN",
     "bucket_symbols", "make_regen_fn",
     "PlanCache", "PlanResult", "PlanStats",
-    "get_planner", "plan_stats", "plan_stats_by_family",
+    "get_planner", "plan_stats",
     "reset_plan_stats", "clear_planners",
     "set_planning", "planning_enabled", "planning_disabled",
 ]

@@ -33,22 +33,32 @@ bucket-ladder-sized host buffers:
 * Dropping a buffer without releasing it is safe (it is simply retired
   from the pool, never reissued), so error paths need no bookkeeping.
 
-The module also owns the process-wide **stage clock**: `record_stage` /
-`stage_times` accumulate wall time per named stage ("pack" for
-flatten/pack257 staging writes, "pad" for planner bucket padding), and
-``Pipeline.stage_stats()`` merges them with its own read/dispatch/
-consume timers into the ``t_stage_read / t_pack / t_pad / t_dispatch /
-t_consume`` accounting BENCH_pipeline reports.
+The module also owns the process-wide **stage clock**, the program's
+one tracing primitive.  ``with staged(name, nbytes, **meta):`` opens a
+profiler span ``repro.<name>`` (a ``jax.profiler.TraceAnnotation``
+carrying ``meta``, so the span sits on the device trace's clock), and
+on exit adds the block's wall seconds, and ``nbytes`` if given, to
+stage ``name``.  `stage_times` / `stage_bytes` read the cumulative
+sums.  With no trace active a span costs one annotation (about a
+microsecond) and one locked add.  Stages: "pack" (bytes <-> symbols,
+pack257, stripe transposes), "pad" (planner bucket padding), "h2d" /
+"d2h" (bytes handed to and pulled from the device), "serialize",
+"format" (npy / npz / CRC bytes), "write" / "fsync" / "read" (node
+files), "ckpt.*" (checkpointer operations) and "pipe.*" (the
+pipeline's read / dispatch / consume callbacks).
+``Pipeline.stage_stats()`` merges "pack" and "pad" with its own
+read/dispatch/consume timers into the ``t_stage_read / t_pack / t_pad /
+t_dispatch / t_consume`` accounting BENCH_pipeline reports.
 """
 from __future__ import annotations
 
 import threading
 from collections import defaultdict
-from contextlib import contextmanager
 from time import perf_counter
 from typing import NamedTuple, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 # Pool buckets ride their own power-of-two ladder from this floor; it
 # deliberately matches the plan cache's BUCKET_MIN so a planner pad of a
@@ -62,42 +72,56 @@ STAGE_NAMES = ("t_stage_read", "t_pack", "t_pad", "t_dispatch",
 # ------------------------------------------------------------ stage clock
 _TLOCK = threading.Lock()
 _TIMES: dict = defaultdict(float)
-_CALLS: dict = defaultdict(int)
+_BYTES: dict = defaultdict(int)
 
 
-def record_stage(name: str, seconds: float) -> None:
-    """Accumulate ``seconds`` of wall time under stage ``name``
-    (thread-safe; called from pool workers and the dispatch thread)."""
+def record_stage(name: str, seconds: float, nbytes: int = 0) -> None:
+    """Add ``seconds`` of wall time and ``nbytes`` bytes to stage
+    ``name`` (thread-safe; called from pool workers and the dispatch
+    thread)."""
     with _TLOCK:
-        _TIMES[name] += float(seconds)
-        _CALLS[name] += 1
+        _TIMES[name] += seconds
+        if nbytes:
+            _BYTES[name] += nbytes
 
 
 def stage_times() -> dict:
-    """Cumulative process-wide seconds per stage since the last reset."""
+    """Cumulative process-wide seconds per stage."""
     with _TLOCK:
         return dict(_TIMES)
 
 
-def stage_calls() -> dict:
+def stage_bytes() -> dict:
+    """Cumulative process-wide bytes per stage, for the stages that
+    count bytes."""
     with _TLOCK:
-        return dict(_CALLS)
+        return dict(_BYTES)
 
 
-def reset_stage_times() -> None:
-    with _TLOCK:
-        _TIMES.clear()
-        _CALLS.clear()
+class staged:
+    """``with staged(name, nbytes=None, **meta) as span:`` -- a profiler
+    span ``repro.<name>`` with ``meta`` as its metadata, whose wall
+    seconds (``span.seconds`` after exit) and ``span.nbytes`` (settable
+    inside the block, for sizes known only at its end) are added to
+    stage ``name``."""
 
+    __slots__ = ("name", "nbytes", "seconds", "_span", "_t0")
 
-@contextmanager
-def staged(name: str):
-    """Time a block under stage ``name``."""
-    t0 = perf_counter()
-    try:
-        yield
-    finally:
-        record_stage(name, perf_counter() - t0)
+    def __init__(self, name: str, nbytes: Optional[int] = None, **meta):
+        self.name = name
+        self.nbytes = nbytes
+        self.seconds = 0.0
+        self._span = TraceAnnotation(f"repro.{name}", **meta)
+
+    def __enter__(self) -> "staged":
+        self._span.__enter__()
+        self._t0 = perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = perf_counter() - self._t0
+        self._span.__exit__(*exc)
+        record_stage(self.name, self.seconds, self.nbytes or 0)
 
 
 # ------------------------------------------------------------------- pool
@@ -218,5 +242,4 @@ class StagingPool:
 
 
 __all__ = ["StagingPool", "StagingStats", "POOL_BUCKET_MIN", "STAGE_NAMES",
-           "record_stage", "stage_times", "stage_calls",
-           "reset_stage_times", "staged"]
+           "record_stage", "stage_times", "stage_bytes", "staged"]

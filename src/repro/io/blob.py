@@ -19,6 +19,10 @@ Durability contract of :class:`LocalBlob`:
 * every write is fsync'd before returning, so a completed ``rename``
   publishes bytes that are actually on the platter;
 * :meth:`fsync_dir` flushes directory entries (the rename itself).
+
+:class:`LocalBlob`'s reads, writes and fsyncs are the "read", "write"
+and "fsync" stages of `repro.exec.staging`: profiler spans named by
+file and directory, with the bytes moved.
 """
 from __future__ import annotations
 
@@ -26,6 +30,8 @@ import os
 import pathlib
 import shutil
 from typing import Union
+
+from repro.exec.staging import staged
 
 PathLike = Union[str, os.PathLike]
 
@@ -81,15 +87,23 @@ class LocalBlob(BlobBackend):
         self.fsync = fsync
 
     def write(self, path: PathLike, data: bytes) -> None:
-        with open(path, "wb") as f:
-            f.write(data)
-            if self.fsync:
-                f.flush()
-                os.fsync(f.fileno())
+        head, name = os.path.split(path)
+        with staged("write", nbytes=len(data), file=name,
+                    dir=os.path.basename(head)):
+            with open(path, "wb") as f:
+                f.write(data)
+                if self.fsync:
+                    f.flush()
+                    with staged("fsync"):
+                        os.fsync(f.fileno())
 
     def read(self, path: PathLike) -> bytes:
-        with open(path, "rb") as f:
-            return f.read()
+        head, name = os.path.split(path)
+        with staged("read", file=name, dir=os.path.basename(head)) as span:
+            with open(path, "rb") as f:
+                data = f.read()
+            span.nbytes = len(data)
+        return data
 
     def exists(self, path: PathLike) -> bool:
         return os.path.exists(path)
@@ -115,11 +129,12 @@ class LocalBlob(BlobBackend):
     def fsync_dir(self, path: PathLike) -> None:
         if not self.fsync:
             return
-        fd = os.open(path, os.O_RDONLY)
-        try:
-            os.fsync(fd)
-        finally:
-            os.close(fd)
+        with staged("fsync", dir=os.path.basename(path)):
+            fd = os.open(path, os.O_RDONLY)
+            try:
+                os.fsync(fd)
+            finally:
+                os.close(fd)
 
 
 def count_tmp_orphans(root: PathLike) -> int:

@@ -78,5 +78,6 @@ def circulant_encode(data: jnp.ndarray, c: tuple[int, ...], p: int = 257, *,
         out_specs=pl.BlockSpec((n, block_s), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((n, s_pad), jnp.int32),
         interpret=interpret,
+        name="gf_circulant_encode",
     )(data)
     return out[:, :s]

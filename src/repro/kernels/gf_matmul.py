@@ -103,5 +103,6 @@ def gf_matmul(a: jnp.ndarray, b: jnp.ndarray, p: int = 257, *,
         out_specs=pl.BlockSpec((block_m, block_s), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m_pad, s_pad), jnp.int32),
         interpret=interpret,
+        name="gf_matmul",
     )(a, b)
     return out[:m, :s]
