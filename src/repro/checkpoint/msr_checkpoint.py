@@ -334,7 +334,7 @@ class MSRCheckpointer:
                          a_block: np.ndarray, r_low: np.ndarray,
                          r_hi: np.ndarray) -> None:
         # repair writes land in committed generations: atomic per file
-        self._write_blob(a_path, _npy_bytes(a_block.astype(np.uint8)),
+        self._write_blob(a_path, _npy_bytes(np.asarray(a_block, np.uint8)),
                          atomic=True)
         self._write_blob(r_path, _npz_bytes(low=r_low, hi=r_hi), atomic=True)
 
@@ -569,7 +569,7 @@ class MSRCheckpointer:
 
     def _save_data_block(self, tmp: pathlib.Path, i: int,
                          block: np.ndarray, crcs: dict) -> None:
-        raw = block.astype(np.uint8)
+        raw = np.asarray(block, np.uint8)       # no copy for uint8 blocks
         crcs[f"node_{i:02d}.a"] = _crc_data(raw)
         self._write_blob(tmp / f"node_{i:02d}.a.npy", _npy_bytes(raw))
 
@@ -624,8 +624,10 @@ class MSRCheckpointer:
         receipt — systematic or degraded, whatever the store served).
         Every checkpoint read path funnels through here via
         :class:`_MeteredReader` so the byte meters can't drift apart.
-        Widening a node file's bytes to int32 symbols is the "pack"
-        stage.
+        A systematic block is returned as the ``uint8`` array np.load
+        gives (data symbols are bytes; the planned executables widen
+        them on the device); unpacking a redundancy block to int32 is
+        the "pack" stage.
         """
         if isinstance(ref, str):
             res = self._store.get_ext(ref)
@@ -637,9 +639,7 @@ class MSRCheckpointer:
                 sym = gf.unpack257(low, hi)
             return sym, low.nbytes + hi.nbytes
         arr = self._load(ref)
-        with staged("pack"):
-            sym = arr.astype(np.int32)
-        return sym, arr.nbytes
+        return arr, arr.nbytes
 
     def _read_packed(self, ref) -> tuple[tuple[np.ndarray, np.ndarray], int]:
         """One packed redundancy read -> ((low, hi), bytes) — the raw
@@ -761,20 +761,25 @@ class MSRCheckpointer:
                 # soon as its rows are copied (the state can be GBs)
                 del futs_help
                 pair = self._regenerate_tiled(pipe, f, r_prev, next_data)
-                a_new, r_new = pair[0], pair[1]
                 af, rf = self._node_files(step, f)
                 with staged("pack"):
-                    low, hi = gf.pack257(r_new)
-                pipe.submit(self._write_node_pair, af, rf, a_new, low, hi)
+                    low, hi = gf.pack257(pair[1])
+                    # every row of data is written below; the rebuilt
+                    # block (values 0..255) is narrowed once, into it
+                    data = np.empty((n, tspec.block_symbols), np.uint8)
+                    data[f - 1] = pair[0]
+                del pair
+                pipe.submit(self._write_node_pair, af, rf, data[f - 1],
+                            low, hi)
                 repaired.append(f)
                 with staged("assemble"):
-                    data = np.zeros((n, tspec.block_symbols), np.int32)
                     have = dict(zip(plan.data_indices, next_data))
-                    have[f - 1] = a_new
                     for i in range(1, n + 1):
                         idx = i - 1
-                        data[idx] = have[idx] if idx in have \
-                            else result(futs_rest.pop(i))
+                        if idx in have:
+                            data[idx] = have[idx]
+                        elif idx != f - 1:
+                            data[idx] = result(futs_rest.pop(i))
                 path = "regenerate"
             else:
                 use = alive[:k]                      # sorted by construction

@@ -240,20 +240,23 @@ def bytes_to_symbols(data: bytes | np.ndarray, p: int = DEFAULT_P) -> np.ndarray
 
 def bytes_to_symbols_into(data: bytes | np.ndarray, out: np.ndarray,
                           p: int = DEFAULT_P) -> np.ndarray:
-    """One-pass byte embedding into a preallocated int32 symbol buffer
-    (zero-copy staging, DESIGN.md §16.1): the uint8 -> int32 cast and
-    the stripe zero-padding land in a single strided write over ``out``
-    instead of the legacy astype -> pad -> astype copy chain.  ``out``
-    must be a flat int32 array at least ``len(data)`` long; the tail
-    past the payload is zeroed.  Counts toward the "pack" stage clock.
+    """One-pass byte embedding into a preallocated symbol buffer
+    (zero-copy staging, DESIGN.md §16.1): the copy (and, into int32,
+    the cast) and the stripe zero-padding land in a single strided write
+    over ``out`` instead of the legacy astype -> pad -> astype copy
+    chain.  ``out`` must be a flat int32 or uint8 array (data symbols
+    are bytes, so uint8 holds them as they are) at least ``len(data)``
+    long; the tail past the payload is zeroed.  Counts toward the
+    "pack" stage clock.
     """
     if p <= 256:
         raise ValueError("byte embedding requires p > 256")
     arr = np.frombuffer(data, dtype=np.uint8) \
         if isinstance(data, (bytes, bytearray)) else np.asarray(data, np.uint8)
-    if out.dtype != np.int32 or out.ndim != 1 or out.size < arr.size:
-        raise ValueError(f"need flat int32 out of >= {arr.size} symbols, "
-                         f"got {out.dtype} {out.shape}")
+    if out.dtype not in (np.int32, np.uint8) or out.ndim != 1 \
+            or out.size < arr.size:
+        raise ValueError(f"need flat int32 or uint8 out of >= {arr.size} "
+                         f"symbols, got {out.dtype} {out.shape}")
     # lazy import: the stage clock lives in repro.exec.staging and core
     # carries no module-level edge into exec (as with the envelope)
     from repro.exec.staging import staged

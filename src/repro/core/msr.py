@@ -158,11 +158,13 @@ class DoubleCirculantMSR:
         Asynchronous — returns a `repro.exec.plan.PlanResult`; call
         ``.host()`` to block and get the exact (n, S) numpy redundancy
         matrix.  Bit-exact vs :meth:`encode` (padding is column-local),
-        with zero trace/compile work at steady state.  Custom-matmul
-        codes fall back to the eager :meth:`encode`.
+        with zero trace/compile work at steady state.  A ``uint8``
+        ``data`` stays one byte a symbol up to the executable, which
+        widens it on the device.  Custom-matmul codes fall back to the
+        eager :meth:`encode`.
         """
         from repro.exec.plan import PlanResult
-        data = np.asarray(data, np.int32)
+        data = np.asarray(data)
         if data.shape[0] != self.n:
             raise ValueError(f"expected {self.n} data blocks, "
                              f"got {data.shape[0]}")

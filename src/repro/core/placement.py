@@ -3,7 +3,10 @@ n = 2k data blocks and back (DESIGN.md §2 — MSR-coded checkpointing).
 
 The mapping is deliberately dumb and auditable:
   pytree -> flat list of (path, dtype, shape, raw bytes) -> one byte stream
-         -> GF(p) symbols -> pad to a multiple of n -> reshape (n, S).
+         -> pad to a multiple of n -> reshape (n, S) uint8 data symbols.
+
+Data symbols are bytes, so the blocks stay ``uint8`` on the host; the
+planned executables widen them to GF(p) int32 lanes on the device.
 
 Systematic property: restoring WITHOUT failures reads only the raw data
 blocks — `blocks_to_pytree(data_blocks)` never touches field arithmetic.
@@ -166,7 +169,9 @@ def pytree_to_bytes(tree: Any) -> tuple[bytes, jax.tree_util.PyTreeDef, list[dic
         return b"".join(chunks), treedef, metas
 
 
-def bytes_to_leaves(payload: bytes, metas: list[dict]) -> list[np.ndarray]:
+def bytes_to_leaves(payload, metas: list[dict]) -> list[np.ndarray]:
+    """The leaves of ``payload`` (bytes, or a flat contiguous uint8
+    array), each copied out of it once."""
     leaves, off = [], 0
     for m in metas:
         dt = np.dtype(m["dtype"])
@@ -180,11 +185,12 @@ def bytes_to_leaves(payload: bytes, metas: list[dict]) -> list[np.ndarray]:
 
 def pytree_to_blocks(tree: Any, n: int, p: int = gf.DEFAULT_P,
                      ) -> tuple[np.ndarray, jax.tree_util.PyTreeDef, TreeSpec]:
-    """Serialize a pytree into (n, S) GF(p) data blocks a_0..a_{n-1}."""
+    """Serialize a pytree into (n, S) ``uint8`` data blocks a_0..a_{n-1}
+    (each byte is a GF(p) data symbol, p > 256)."""
     payload, treedef, metas = pytree_to_bytes(tree)
-    # one int32 allocation: the widen, the pad to a multiple of n and the
-    # (n, S) layout land in a single write (the state can be GBs)
-    blocks = np.empty((n, -(-len(payload) // n)), np.int32)
+    # one allocation: the copy, the pad to a multiple of n and the (n, S)
+    # layout land in a single write (the state can be GBs)
+    blocks = np.empty((n, -(-len(payload) // n)), np.uint8)
     gf.bytes_to_symbols_into(payload, blocks.reshape(-1), p)
     spec = TreeSpec(treedef_repr=str(treedef), leaves=metas,
                     total_bytes=len(payload), n_blocks=n,
@@ -195,13 +201,18 @@ def pytree_to_blocks(tree: Any, n: int, p: int = gf.DEFAULT_P,
 def blocks_to_pytree(blocks: np.ndarray, treedef: jax.tree_util.PyTreeDef,
                      spec: TreeSpec) -> Any:
     """Inverse of pytree_to_blocks.  Pure byte reads for systematic blocks.
-    Runs as the "deserialize" stage, narrowing the symbols back to bytes
-    as a "pack" span inside it."""
+    Runs as the "deserialize" stage.  ``uint8`` blocks are the payload
+    and are read in place; int32 symbols are range-checked and narrowed
+    back to bytes as a "pack" span inside it."""
     from repro.exec.staging import staged
     with staged("deserialize"):
-        sym = np.asarray(blocks).reshape(-1)[: spec.total_bytes]
-        with staged("pack"):
-            payload = gf.symbols_to_bytes(sym)
+        sym = np.asarray(blocks)
+        if sym.dtype == np.uint8:
+            payload = np.ascontiguousarray(sym).reshape(-1)[: spec.total_bytes]
+        else:
+            with staged("pack"):
+                payload = gf.symbols_to_bytes(
+                    sym.reshape(-1)[: spec.total_bytes])
         leaves = bytes_to_leaves(payload, spec.leaves)
         return jax.tree_util.tree_unflatten(treedef, leaves)
 

@@ -312,7 +312,7 @@ class RepairEngine:
         back to :meth:`apply` (per-shape jit) without a planner."""
         if self._planned():
             return self.planner.matmul(mat, blocks)
-        blocks = np.asarray(blocks, np.int32)
+        blocks = np.asarray(blocks)
         return PlanResult(self.apply(mat, blocks), blocks.shape[-1])
 
     def regenerate_stacked(self, i: int, r_prev, next_data) -> jnp.ndarray:
@@ -342,8 +342,9 @@ class RepairEngine:
     def regenerate_planned(self, i: int, r_prev, next_data) -> PlanResult:
         """Planned fused newcomer compute: the (2, k+1) repair-matrix
         application through one bucketed AOT executable per (k, bucket).
-        Same contract as :meth:`regenerate_stacked`, asynchronous."""
-        next_data = np.asarray(next_data, np.int32)
+        Same contract as :meth:`regenerate_stacked`, asynchronous; a
+        ``uint8`` ``next_data`` is widened inside the executable."""
+        next_data = np.asarray(next_data)
         if next_data.shape[0] != self.k:
             raise ValueError(f"expected {self.k} helper data blocks, "
                              f"got {next_data.shape[0]}")
@@ -360,7 +361,7 @@ class RepairEngine:
         drain share one executable); ``.host()`` returns the exact
         (F, 2, S) stack.  Falls back to :meth:`regenerate_batch`."""
         r_prevs = np.asarray(r_prevs, np.int32)
-        next_data = np.asarray(next_data, np.int32)
+        next_data = np.asarray(next_data)
         f = len(nodes)
         if r_prevs.shape[0] != f or next_data.shape[:2] != (f, self.k):
             raise ValueError(f"helper shapes {r_prevs.shape}/{next_data.shape}"

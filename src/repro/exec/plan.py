@@ -23,6 +23,10 @@ The :class:`PlanCache` removes that cost structurally:
   whenever an output can actually alias it (encode's (n, S) -> (n, S),
   the square any-k decode) — the padded staging buffer is dead after
   the call, so XLA reuses it instead of allocating;
+* a ``uint8`` stream operand (data symbols, one byte each) is shipped
+  as bytes and widened to int32 inside the executable; its dtype joins
+  the plan key, int32 keys stay as they were, and it is never donated
+  (an int32 output cannot alias a byte buffer);
 * :func:`plan_stats` exposes lifetime hits / misses / compiles across
   every live planner, which is how the recompile-regression test and
   ``benchmarks/bench_pipeline.py`` assert the steady-state guarantee:
@@ -205,10 +209,19 @@ class PlanResult:
         return out if dtype is None else out.astype(dtype)
 
 
+def _stream(arr) -> np.ndarray:
+    """A stream operand as the planner ships it: ``uint8`` (data symbols,
+    one byte each) stays ``uint8`` and is widened inside the executable;
+    anything else is int32 symbols, as before."""
+    arr = np.asarray(arr)
+    return arr if arr.dtype == np.uint8 else arr.astype(np.int32, copy=False)
+
+
 def _pad_last(arr: np.ndarray, bucket: int,
               pool: Optional["StagingPool"] = None,
               bufs: Optional[list] = None) -> np.ndarray:
-    """Zero-pad the stream (last) axis up to ``bucket``.
+    """Zero-pad the stream (last) axis up to ``bucket``, in the operand's
+    dtype (:func:`_stream`).
 
     JAX reads host operands asynchronously (after dispatch returns), so
     a scratch buffer may not be reused while an in-flight compute still
@@ -219,16 +232,16 @@ def _pad_last(arr: np.ndarray, bucket: int,
     the historical always-fresh buffer keeps the same safety the hard
     way.
     """
-    arr = np.asarray(arr, np.int32)
+    arr = _stream(arr)
     s = arr.shape[-1]
     if s == bucket:
         return arr
     with staged("pad"):
         if pool is None:
-            out = np.zeros(arr.shape[:-1] + (bucket,), np.int32)
+            out = np.zeros(arr.shape[:-1] + (bucket,), arr.dtype)
             out[..., :s] = arr
         else:
-            out = pool.acquire(arr.shape[:-1] + (bucket,), np.int32)
+            out = pool.acquire(arr.shape[:-1] + (bucket,), arr.dtype)
             out[..., :s] = arr
             out[..., s:] = 0        # reused buffer: tail must be re-zeroed
             bufs.append(out)
@@ -241,17 +254,17 @@ def _pad_both(arr: np.ndarray, f_bucket: int, s_bucket: int,
     """Pad axis 0 to ``f_bucket`` and the last axis to ``s_bucket`` in
     one copy (the batched-regenerate operands); pooled like
     :func:`_pad_last` when ``pool`` is set."""
-    arr = np.asarray(arr, np.int32)
+    arr = _stream(arr)
     f, s = arr.shape[0], arr.shape[-1]
     if f == f_bucket and s == s_bucket:
         return arr
     with staged("pad"):
         shape = (f_bucket,) + arr.shape[1:-1] + (s_bucket,)
         if pool is None:
-            out = np.zeros(shape, np.int32)
+            out = np.zeros(shape, arr.dtype)
             out[:f, ..., :s] = arr
         else:
-            out = pool.acquire(shape, np.int32)
+            out = pool.acquire(shape, arr.dtype)
             out[...] = 0
             out[:f, ..., :s] = arr
             bufs.append(out)
@@ -346,15 +359,28 @@ class PlanCache:
         sb = self.bucket(self.mesh.shard_extent(s))
         return sb, sb * self.mesh.size
 
-    def _i32(self, *shapes):
-        return [jax.ShapeDtypeStruct(s, jnp.int32) for s in shapes]
+    def _compile(self, op: str, fn: Callable, shapes, donate=(),
+                 dtypes=None):
+        """Lower + AOT-compile ``fn`` at ``shapes`` (int32 operands unless
+        ``dtypes`` names each one's): plain jit when unsharded,
+        ``jit(shard_map(fn))`` under the op's registered sharding rule
+        when meshed (inputs/outputs pinned to the rule's NamedShardings,
+        so host numpy operands are scattered straight to their
+        per-device shards at call time).
 
-    def _compile(self, op: str, fn: Callable, shapes, donate=()):
-        """Lower + AOT-compile ``fn`` at ``shapes``: plain jit when
-        unsharded, ``jit(shard_map(fn))`` under the op's registered
-        sharding rule when meshed (inputs/outputs pinned to the rule's
-        NamedShardings, so host numpy operands are scattered straight to
-        their per-device shards at call time)."""
+        ``fn`` widens every operand to int32 first, so a ``uint8`` data
+        operand crosses the link at one byte a symbol and is widened on
+        the device; for int32 operands the cast traces to nothing.  Only
+        int32 operands are donated: an int32 output cannot alias a byte
+        buffer."""
+        if dtypes is None:
+            dtypes = (np.int32,) * len(shapes)
+        donate = tuple(i for i in donate if dtypes[i] == np.int32)
+        body = fn
+
+        def fn(*xs):
+            return body(*(x.astype(jnp.int32) for x in xs))
+
         if self.mesh is None:
             jf = jax.jit(fn, donate_argnums=donate)
         else:
@@ -363,7 +389,8 @@ class PlanCache:
             jf = jax.jit(shard_body(fn, op, self.mesh),
                          in_shardings=self.mesh.shardings(rule.in_specs),
                          out_shardings=self.mesh.sharding(rule.out_specs))
-        return jf.lower(*self._i32(*shapes)).compile()
+        return jf.lower(*(jax.ShapeDtypeStruct(s, d)
+                          for s, d in zip(shapes, dtypes))).compile()
 
     def _exe(self, key: tuple, build: Callable[[], Callable]) -> Callable:
         with self._lock:
@@ -405,6 +432,12 @@ class PlanCache:
         recompiles ride along with the tagging feature."""
         return key if tag is None else key + (tag,)
 
+    @staticmethod
+    def _typed(key: tuple, dtype) -> tuple:
+        """Mix a non-int32 stream dtype (``uint8`` data symbols) into a
+        plan key; int32 operands keep their key and executable."""
+        return key if dtype == np.int32 else key + (np.dtype(dtype).name,)
+
     def plan_stats(self) -> PlanStats:
         return PlanStats(self.hits, self.misses, self.compiles)
 
@@ -431,12 +464,13 @@ class PlanCache:
         the plan key (DESIGN.md §15.4).
         """
         mat = np.asarray(mat, np.int32)
-        blocks = np.asarray(blocks, np.int32)
+        blocks = _stream(blocks)
         s = blocks.shape[-1]
         if not _ENABLED:
             return PlanResult(self.backend.matmul(mat, blocks, self.p), s)
         b, pad = self.stream_pad(s)
-        key = self._tagged(("matmul", mat.shape, blocks.shape[:-1], b), tag)
+        key = self._typed(self._tagged(
+            ("matmul", mat.shape, blocks.shape[:-1], b), tag), blocks.dtype)
         # donation is only usable when an output can alias the donated
         # buffer, i.e. the product has the stream operand's exact shape
         # (square decode matrices: the (n, n) any-k inverse) — donating
@@ -448,7 +482,7 @@ class PlanCache:
             fn = lambda a, x: self.backend.matmul(a, x, self.p)
             return self._compile("matmul", fn,
                                  (mat.shape, blocks.shape[:-1] + (pad,)),
-                                 donate)
+                                 donate, (np.int32, blocks.dtype))
 
         bufs: list = []
         padded = _pad_last(blocks, pad, self.staging, bufs)
@@ -463,20 +497,21 @@ class PlanCache:
         so it is part of the plan key — one executable per code, not per
         call.
         """
-        data = np.asarray(data, np.int32)
+        data = _stream(data)
         c = tuple(int(x) for x in c)
         s = data.shape[-1]
         if not _ENABLED:
             return PlanResult(self.backend.circulant_encode(data, c, self.p),
                               s)
         b, pad = self.stream_pad(s)
-        key = self._tagged(("circ", data.shape[0], c, b), tag)
+        key = self._typed(self._tagged(("circ", data.shape[0], c, b), tag),
+                          data.dtype)
 
         def build():
             fn = lambda d: self.backend.circulant_encode(d, c, self.p)
             return self._compile("circulant_encode", fn,
                                  ((data.shape[0], pad),),
-                                 (0,) if self.donate else ())
+                                 (0,) if self.donate else (), (data.dtype,))
 
         bufs: list = []
         padded = _pad_last(data, pad, self.staging, bufs)
@@ -489,20 +524,21 @@ class PlanCache:
         epilogue on r_prev, one executable per (k, bucket)."""
         rmat = np.asarray(rmat, np.int32)
         r_prev = np.asarray(r_prev, np.int32)
-        next_data = np.asarray(next_data, np.int32)
+        next_data = _stream(next_data)
         s = r_prev.shape[-1]
         if not _ENABLED:
             return PlanResult(
                 self._regen_fn()(rmat, r_prev, next_data), s)
         b, pad = self.stream_pad(s)
         k = next_data.shape[0]
-        key = ("regen", k, b)
+        key = self._typed(("regen", k, b), next_data.dtype)
 
         def build():
             # the (2, S) pair can alias next_data only at k == 2
             donate = (2,) if self.donate and k == 2 else ()
             return self._compile("regenerate", self._regen_fn(),
-                                 (rmat.shape, (pad,), (k, pad)), donate)
+                                 (rmat.shape, (pad,), (k, pad)), donate,
+                                 (np.int32, np.int32, next_data.dtype))
 
         bufs: list = []
         return PlanResult(self._run(
@@ -521,7 +557,7 @@ class PlanCache:
         """
         rmat = np.asarray(rmat, np.int32)
         r_prevs = np.asarray(r_prevs, np.int32)
-        next_data = np.asarray(next_data, np.int32)
+        next_data = _stream(next_data)
         s = r_prevs.shape[-1]
         f, k = next_data.shape[0], next_data.shape[1]
         if not _ENABLED:
@@ -530,7 +566,7 @@ class PlanCache:
                 r_prevs, next_data), s, batch=f)
         b, pad = self.stream_pad(s)
         fb = self.batch_bucket(f)
-        key = ("regen_batch", fb, k, b)
+        key = self._typed(("regen_batch", fb, k, b), next_data.dtype)
 
         def build():
             one = self._regen_fn()
@@ -542,7 +578,7 @@ class PlanCache:
             donate = (2,) if self.donate and k == 2 else ()
             return self._compile("regenerate_batch", fn,
                                  (rmat.shape, (fb, pad), (fb, k, pad)),
-                                 donate)
+                                 donate, (np.int32, np.int32, next_data.dtype))
 
         bufs: list = []
         return PlanResult(self._run(

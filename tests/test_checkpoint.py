@@ -187,6 +187,82 @@ def test_bit_exact_across_dtypes(tmp_path):
     assert_state_equal(got, state)
 
 
+def _node_file_contents(step_dir):
+    """{file: bytes} of a step's node files: each ``.a.npy`` whole, each
+    ``.r.npz`` member by member (the zip container stamps the time)."""
+    import zipfile
+    out = {}
+    for f in sorted(step_dir.iterdir()):
+        if f.name.endswith(".a.npy"):
+            out[f.name] = f.read_bytes()
+        elif f.name.endswith(".r.npz"):
+            with zipfile.ZipFile(f) as z:
+                for m in sorted(z.namelist()):
+                    out[f"{f.name}/{m}"] = z.read(m)
+    return out
+
+
+@pytest.mark.parametrize("path", ["systematic", "regenerate", "reconstruct",
+                                  "repair_node", "scrub"])
+def test_uint8_blocks_roundtrip(tmp_path, monkeypatch, path):
+    """Data blocks stay uint8 from the pytree to the planned executable;
+    the node files and manifest are those an int32 (n, S) of the same
+    values writes, and every restore path still gives the state back."""
+    from repro.core import placement
+    spec = CodeSpec.make(4, 257)
+    state = make_state(5)
+    blocks, _, _ = placement.pytree_to_blocks(state, spec.n)
+    assert blocks.dtype == np.uint8
+    ckpt = MSRCheckpointer(tmp_path / "u8", spec)
+    ckpt.save(1, state)
+    step_dir = ckpt._step_dir(1)
+    files = _node_file_contents(step_dir)
+
+    to_blocks = placement.pytree_to_blocks
+
+    def widened(*args, **kwargs):
+        blocks, treedef, tspec = to_blocks(*args, **kwargs)
+        return blocks.astype(np.int32), treedef, tspec
+
+    with monkeypatch.context() as m:
+        m.setattr(placement, "pytree_to_blocks", widened)
+        ref = MSRCheckpointer(tmp_path / "i32", spec)
+        ref.save(1, state)
+    assert _node_file_contents(ref._step_dir(1)) == files
+    assert (step_dir / "manifest.json").read_bytes() == \
+        (ref._step_dir(1) / "manifest.json").read_bytes()
+
+    if path == "scrub":
+        report = ckpt.scrub(1)
+        assert report.clean and report.nodes_checked == spec.n
+    elif path == "repair_node":
+        for f in ckpt._node_files(1, 3):
+            f.unlink()
+        ckpt.repair_node(1, 3)
+    else:
+        failed = {"systematic": [], "regenerate": [3],
+                  "reconstruct": [2, 6]}[path]
+        for node in failed:
+            for f in ckpt._node_files(1, node):
+                f.unlink()
+        got, report = ckpt.restore(state, 1, failed_nodes=failed)
+        assert report.path == path
+        assert_state_equal(got, state)
+    assert _node_file_contents(step_dir) == files   # rebuilt bit-exactly
+
+
+def test_coded_read_server_roundtrips_uint8_blocks():
+    """The serving layer encodes ``pytree_to_blocks``' uint8 blocks and
+    rebuilds the pytree from the simulator's int32 reads."""
+    from repro.serve.engine import CodedReadServer
+    spec = CodeSpec.make(4, 257)
+    state = make_state(6)
+    srv = CodedReadServer.for_pytree(state, spec)
+    assert_state_equal(srv.read_state(), state)
+    srv.sim.fail_node(3)
+    assert_state_equal(srv.read_state(), state)
+
+
 # ------------------------------------------- crash consistency (DESIGN.md §12)
 from repro.io import (FaultInjector, FaultyBlob, GiveUpError, LocalBlob,
                       count_tmp_orphans, fast_retry)

@@ -116,6 +116,69 @@ class TestPlannedOpsBitExact:
         assert pc.plan_stats().compiles == 0     # bypassed entirely
 
 
+# ---------------------------------------------------- uint8 data operands
+def _u8_case(op, code, rng, s):
+    """(call, data) for one planned op: ``call(pc, x)`` runs ``op`` with
+    ``x`` as its data-symbol stream operand (values 0..255)."""
+    data = rng.integers(0, 256, (code.n, s)).astype(np.int32)
+    red = np.asarray(code.encode(data))
+    rmat = code.repair.repair_matrix()
+    nodes = [2, 5, 7]
+    r_prevs = np.stack([red[code.repair_plan(i).prev_node - 1]
+                        for i in nodes])
+    idx = [list(code.repair_plan(i).data_indices) for i in nodes]
+    if op == "circulant_encode":
+        return (lambda pc, x: pc.circulant_encode(x, tuple(SPEC.c))), data
+    if op == "matmul":
+        mat = rng.integers(0, P, (code.n, code.n)).astype(np.int32)
+        return (lambda pc, x: pc.matmul(mat, x)), data
+    if op == "regenerate":
+        return (lambda pc, x: pc.regenerate(rmat, r_prevs[0], x)), \
+            data[idx[0]]
+    return (lambda pc, x: pc.regenerate_batch(rmat, r_prevs, x)), \
+        np.stack([data[i] for i in idx])
+
+
+@pytest.mark.parametrize("op", ["circulant_encode", "regenerate",
+                                "regenerate_batch", "matmul"])
+def test_uint8_stream_operand_matches_int32(op, monkeypatch):
+    """A uint8 data operand gives the int32 operand's exact result (odd
+    stream tails padded in uint8), compiles one executable of its own
+    that a second extent in the bucket reuses, and leaves the int32 key
+    and executable as they were; donation is off for it and the
+    unplanned fallback takes it too."""
+    rng = np.random.default_rng(11)
+    code = DoubleCirculantMSR(SPEC)
+    pc = PlanCache(dispatch.get("jnp-int32"), P, bucket_min=32,
+                   donate=True)
+    donated = []
+    jit = plan_mod.jax.jit
+
+    def spy(fn, donate_argnums=(), **kw):
+        donated.append(donate_argnums)
+        return jit(fn, donate_argnums=donate_argnums, **kw)
+
+    monkeypatch.setattr(plan_mod.jax, "jit", spy)
+    call, x32 = _u8_case(op, code, rng, 45)         # bucket 64: padded
+    ref = call(pc, x32).host()
+    (k32,) = pc._plans
+    assert "uint8" not in k32
+    got = call(pc, x32.astype(np.uint8)).host()
+    np.testing.assert_array_equal(got, ref)
+    assert set(pc._plans) == {k32, k32 + ("uint8",)}
+    assert donated[1] == ()
+    assert pc.plan_stats().compiles == 2
+    # a second extent in the same bucket: no compile, for either dtype
+    call2, y32 = _u8_case(op, code, rng, 51)
+    np.testing.assert_array_equal(call2(pc, y32.astype(np.uint8)).host(),
+                                  call2(pc, y32).host())
+    assert pc.plan_stats().compiles == 2
+    with plan_mod.planning_disabled():
+        np.testing.assert_array_equal(
+            np.asarray(call(pc, x32.astype(np.uint8)).host()), ref)
+    assert pc.plan_stats().compiles == 2
+
+
 # -------------------------------------------------------- cache accounting
 class TestPlanStats:
     def test_hits_misses_compiles(self):

@@ -20,7 +20,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import repair
 from repro.core.circulant import CodeSpec
-from repro.exec.plan import PlanCache
+from repro.exec.plan import PlanCache, make_regen_fn
 from repro.kernels import dispatch
 from repro.kernels.circulant_encode import circulant_encode
 from repro.kernels.gf_matmul import gf_matmul
@@ -85,6 +85,26 @@ def test_vmapped_fused_regeneration_compiles_for_v5e(chip):
     _native(repair._fused_regenerate_vmapped.lower(
         mm, chip((2, k + 1)), chip((f, s)), chip((f, k, s)), p=257
     ).compile())
+
+
+@pytest.mark.parametrize("op", ["circulant_encode", "regenerate"])
+def test_byte_operand_plans_compile_for_v5e(topo, op):
+    """The checkpointer's plans take uint8 data symbols and widen them
+    to int32 on the chip ahead of the kernel, at the stream tile of a
+    save and a restore."""
+    one = SingleDeviceSharding(topo.devices[0])
+    u8 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=one)
+    i32 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+    backend, spec, s = dispatch.get("pallas"), CodeSpec.make(4, 257), 1 << 20
+    if op == "circulant_encode":
+        fn = lambda d: backend.circulant_encode(d.astype(jnp.int32),
+                                                spec.c, 257)
+        args = (u8((spec.n, s)),)
+    else:
+        regen = make_regen_fn(backend.matmul, 257)
+        fn = lambda rm, rp, nd: regen(rm, rp, nd.astype(jnp.int32))
+        args = (i32((2, spec.k + 1)), i32((s,)), u8((spec.k, s)))
+    _native(jax.jit(fn).lower(*args).compile())
 
 
 def test_sharded_encode_plan_compiles_over_four_v5e_chips(topo):

@@ -124,11 +124,12 @@ def test_staged_counts_a_block_that_raises():
 
 
 @pytest.mark.parametrize("op", ["save", "restore"])
-def test_link_bytes_are_the_padded_int32_operands(tmp_path, op):
-    """A save ships the (n, S) int32 blocks padded to their bucket and
-    pulls the (n, S_pad) redundancy back, besides the device leaves; a
-    single-node regeneration ships the repair matrix, r_prev and the k
-    helper blocks and pulls the (2, S_pad) pair."""
+def test_link_bytes_are_the_padded_operands(tmp_path, op):
+    """A save ships the (n, S) uint8 data blocks padded to their bucket
+    and pulls the (n, S_pad) int32 redundancy back, besides the device
+    leaves; a single-node regeneration ships the int32 repair matrix and
+    r_prev and the k uint8 helper blocks, and pulls the (2, S_pad) int32
+    pair."""
     ckpt = MSRCheckpointer(tmp_path, SPEC)
     state = make_state()
     s_sym = -(-sum(np.asarray(x).nbytes
@@ -139,11 +140,11 @@ def test_link_bytes_are_the_padded_int32_operands(tmp_path, op):
     b0 = staging.stage_bytes()
     if op == "save":
         ckpt.save(1, state)
-        h2d = SPEC.n * s_pad * 4
+        h2d = SPEC.n * s_pad
         d2h = SPEC.n * s_pad * 4 + device_bytes(state)
     else:
         ckpt.restore(state, 1, failed_nodes=[2])
-        h2d = 2 * (SPEC.k + 1) * 4 + (SPEC.k + 1) * s_pad * 4
+        h2d = 2 * (SPEC.k + 1) * 4 + s_pad * 4 + SPEC.k * s_pad
         d2h = 2 * s_pad * 4
     b1 = staging.stage_bytes()
     assert b1["h2d"] - b0.get("h2d", 0) == h2d
