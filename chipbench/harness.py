@@ -2,9 +2,10 @@
 
 Everything a cell needs is found by name from ``BENCHMARK.json``: the
 cell's ``config`` file (``configs/<config>.json``), its traffic mix
-(``traffic/<traffic>.json``, run by ``generator.py``) and a reader for
-each of its metrics (``metrics/<name>.py``, else ``metrics/<stem>.py``
-for a name ``<stem>.<suffix>``).  Nothing here knows a cell by name.
+(``traffic/<traffic>.json``, run by the loop ``loops/<loop>.py`` that
+the mix names) and a reader for each of its metrics
+(``metrics/<name>.py``, else ``metrics/<stem>.py`` for a name
+``<stem>.<suffix>``).  Nothing here knows a cell by name.
 
 The result is one JSON line, the last of standard output.  Earlier lines
 say what the run did: the GF backend, compiles and cache hits in set-up
@@ -198,6 +199,10 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
 
     cell, cfg_file, mix = resolve(bench, cell_name)
     config = config or cfg_file
+    if config.get("host_chips", 1) > cell["chips"]:
+        raise BenchError(f"config {cell['config']!r} holds its state on "
+                         f"{config['host_chips']} chips; cell "
+                         f"{cell_name!r} has {cell['chips']}")
     clock = CompileClock()
     clock.install()
     phases: dict[str, float] = {"runtime_up": time.perf_counter() - t_start}
@@ -235,6 +240,7 @@ def _run_loop(bench: dict, cell: dict, loop, seconds: float, trace: bool,
     peaks = peaks or peaks_for(dev.device_kind)
     backend = _backend_name()
     c_win, st0, pl0 = clock.totals(), staging.stage_times(), plan.plan_stats()
+    sb0 = staging.stage_bytes()
     tdir = tempfile.mkdtemp(prefix="chipbench_trace_") if trace else None
     if trace:
         trace_mod.start(tdir)
@@ -246,12 +252,14 @@ def _run_loop(bench: dict, cell: dict, loop, seconds: float, trace: bool,
         if trace:
             jax.profiler.stop_trace()
     c_end, st1, pl1 = clock.totals(), staging.stage_times(), plan.plan_stats()
+    sb1 = staging.stage_bytes()
     window_compiles = CompileClock.delta(c_end, c_win)
     device = device_info(cell["chips"])
     reduced = None
     if trace:
         try:
-            reduced = trace_mod.reduce_dir(tdir, gf_modules=_gf_modules())
+            reduced = trace_mod.reduce_dir(tdir, gf_modules=_gf_modules(),
+                                           chips=cell["chips"])
         finally:
             shutil.rmtree(tdir, ignore_errors=True)
         device["busy_s"] = reduced["busy_s"]
@@ -280,7 +288,9 @@ def _run_loop(bench: dict, cell: dict, loop, seconds: float, trace: bool,
          peak_host_rss_bytes=peak_rss(),
          peak_hbm_bytes=device_peaks(cell["chips"]), device=device,
          work_bytes=loop.work_bytes, gf_needed_bytes=loop.gf_bytes,
-         stage_delta=ctx.stage_delta, plan_delta=ctx.plan_delta, **loop.facts)
+         stage_delta=ctx.stage_delta,
+         stage_bytes_delta={k: v - sb0.get(k, 0) for k, v in sb1.items()},
+         plan_delta=ctx.plan_delta, **loop.facts)
     if reduced is not None:
         info(gf_device_s=reduced["gf_device_s"],
              executables=reduced["executables"][:20])

@@ -8,18 +8,22 @@ them the ``XLA Ops`` line holds every operation that ran, and the ``XLA
 Modules`` line the executable each belongs to; ``Async XLA Ops`` holds
 the asynchronous copies, busy as well.  The benchmark's own host
 spans are ``TraceAnnotation`` events named ``bench.<what>`` on the host
-plane, and ``bench.window`` brackets the measured window.
+plane, and ``bench.window`` brackets the measured window; the program's
+spans are named ``repro.<what>``.
 
-The reduction, over the window:
+The reduction, over the window and the cell's ``chips`` devices (the
+planes ``/device:TPU:0`` .. ``/device:TPU:<chips-1>``):
 
-* busy -- the union of the device's operation intervals, averaged over
+* busy -- the union of each device's operation intervals, averaged over
   the devices; the idle share is 1 - busy / window;
 * executables -- device seconds per executable (module name without its
-  program id), and among them the plan cache's GF executables, named by
-  the caller: device time is attributed by executable, not by kernel;
+  program id), averaged over the devices, and among them the plan
+  cache's GF executables, named by the caller: device time is attributed
+  by executable, not by kernel;
 * device_ops -- the ten operations that took most device time;
 * idle_gaps -- the ten longest gaps between busy intervals of the first
-  device, each named by the innermost ``bench.*`` span the host was in.
+  device, each named by the innermost ``bench.*`` or ``repro.*`` span
+  the host was in.
 """
 from __future__ import annotations
 
@@ -34,10 +38,11 @@ from jax.profiler import ProfileData
 OPS_LINE = "XLA Ops"
 ASYNC_LINE = "Async XLA Ops"
 MODULES_LINE = "XLA Modules"
-SPAN_PREFIX = "bench."
+SPAN_PREFIX = ("bench.", "repro.")
 WINDOW_SPAN = "bench.window"
 TOP = 10
 _PROGRAM_ID = re.compile(r"\(\d+\)$")
+_DEVICE = re.compile(r"/device:TPU:(\d+)")
 
 
 def start(log_dir: str) -> None:
@@ -89,10 +94,22 @@ def op_name(event: str) -> str:
     return event.split(" = ", 1)[0].lstrip("%")
 
 
-def reduce(planes: list, gf_modules: set,
+def chip_planes(planes: list, chips: int) -> list:
+    """(index, {line name: events}) of the planes of the first ``chips``
+    devices, by index."""
+    out = []
+    for name, lines in planes:
+        m = _DEVICE.fullmatch(name)
+        if m and int(m.group(1)) < chips:
+            out.append((int(m.group(1)), dict(lines)))
+    return sorted(out, key=lambda d: d[0])
+
+
+def reduce(planes: list, gf_modules: set, chips: int,
            window_span: str = WINDOW_SPAN) -> dict:
-    """The window's numbers from ``planes_of`` output (see module doc);
-    ``window_span`` names the host span that brackets the window."""
+    """The window's numbers from ``planes_of`` output over the first
+    ``chips`` devices (see module doc); ``window_span`` names the host
+    span that brackets the window."""
     spans = []
     for name, lines in planes:
         if name.startswith("/device"):
@@ -104,19 +121,17 @@ def reduce(planes: list, gf_modules: set,
         raise ValueError(f"no {window_span} span in the trace")
     lo = min(ev[1] for ev in windows)
     hi = max(ev[2] for ev in windows)
-    devices = [(name, dict(lines)) for name, lines in planes
-               if name.startswith("/device:TPU:")
-               and OPS_LINE in dict(lines)]
+    devices = chip_planes(planes, chips)
     busy_each, execs, ops = [], {}, {}
     first_busy: list = []
-    for i, (_name, lines) in enumerate(devices):
+    for i, (_index, lines) in enumerate(devices):
+        dev_ops = lines.get(OPS_LINE, [])
         busy = union(_clip([(s, e) for _n, s, e in
-                            lines[OPS_LINE] + lines.get(ASYNC_LINE, [])],
-                           lo, hi))
+                            dev_ops + lines.get(ASYNC_LINE, [])], lo, hi))
         busy_each.append(sum(e - s for s, e in busy))
         if i == 0:
             first_busy = busy
-        for name, s, e in lines[OPS_LINE]:
+        for name, s, e in dev_ops:
             for cs, ce in _clip([(s, e)], lo, hi):
                 ops[op_name(name)] = ops.get(op_name(name), 0.0) + (ce - cs)
         for name, s, e in lines.get(MODULES_LINE, []):
@@ -149,9 +164,10 @@ def reduce(planes: list, gf_modules: set,
     }
 
 
-def reduce_dir(log_dir: str, gf_modules: set) -> dict:
+def reduce_dir(log_dir: str, gf_modules: set, chips: int) -> dict:
     paths = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
                       recursive=True)
     if len(paths) != 1:
         raise ValueError(f"expected one trace under {log_dir}, got {paths}")
-    return reduce(planes_of(ProfileData.from_file(paths[0])), gf_modules)
+    return reduce(planes_of(ProfileData.from_file(paths[0])), gf_modules,
+                  chips)
